@@ -1,9 +1,12 @@
 """Roofline analysis (EXPERIMENTS §Roofline): three terms per (arch ×
 shape) on the single-pod mesh, derived from the dry-run artifacts.
 
-    compute    = FLOPs_dev / 197e12            (bf16 MXU peak per chip)
-    memory     = HLO_bytes_dev / 819e9         (HBM bandwidth per chip)
-    collective = coll_bytes_dev / 50e9         (ICI per link)
+    compute    = FLOPs_dev / peak FLOP/s       (bf16 MXU peak per chip)
+    memory     = HLO_bytes_dev / HBM B/s       (HBM bandwidth per chip)
+    collective = coll_bytes_dev / ICI B/s      (ICI per link)
+
+with the peaks of the target chip (TPU v5e) read from the one
+device-kind table, ``repro.obs.cost.PEAKS``.
 
 All inputs are PER-DEVICE (verified: XLA cost_analysis reports post-SPMD
 per-device numbers) with while-loop undercount corrected by the unrolled
@@ -17,10 +20,10 @@ from __future__ import annotations
 import json
 import sys
 
-PEAK_FLOPS = 197e12        # bf16 / chip (TPU v5e class)
-HBM_BW = 819e9             # B/s per chip
-ICI_BW = 50e9              # B/s per link
-N_DEV = 256
+from repro.obs.cost import PEAKS
+
+#: the dry-run's target chip
+TARGET = PEAKS["TPU v5 lite"]
 
 
 def model_flops(cfg, shape_kind, seq_len, global_batch):
@@ -50,9 +53,9 @@ def analyze(cells, *, with_probes=True):
         bytes_dev = probe.get("hlo_bytes", c["hlo_bytes"])
         coll_dev = probe.get("collective_bytes_total",
                              c["collective_bytes"].get("total", 0))
-        t_comp = flops_dev / PEAK_FLOPS
-        t_mem = bytes_dev / HBM_BW
-        t_coll = coll_dev / ICI_BW
+        t_comp = flops_dev / TARGET.flops
+        t_mem = bytes_dev / TARGET.mem_bw
+        t_coll = coll_dev / TARGET.collective_bw
         dominant = max((("compute", t_comp), ("memory", t_mem),
                         ("collective", t_coll)), key=lambda kv: kv[1])[0]
         cfg = get_config(c["arch"])
@@ -63,7 +66,7 @@ def analyze(cells, *, with_probes=True):
         # roofline fraction: useful work over the time the dominant term
         # implies (= achievable MFU bound for this artifact)
         t_star = max(t_comp, t_mem, t_coll)
-        frac = (mf_dev / PEAK_FLOPS) / max(t_star, 1e-30)
+        frac = (mf_dev / TARGET.flops) / max(t_star, 1e-30)
         mem = c["memory"]
         hbm = ((mem["argument_size"] or 0) + (mem["temp_size"] or 0)
                + (mem["output_size"] or 0)) / 2 ** 30

@@ -471,21 +471,26 @@ def get_blocks(kernel: str, n: int, d: int, dtype, interpret: bool,
         _count_dispatch(kernel, source)
         return int(hit["bn"]), int(hit["bd"])
     if tune_call is not None and autotune_enabled():
-        best, best_t = None, float("inf")
+        best, best_t, errors = None, float("inf"), []
         for bn, bd in _candidates(n, d, interpret):
             try:
                 t = _time_call(lambda: tune_call(bn, bd))
-            except Exception:
+            except Exception as e:  # noqa: BLE001 — a candidate may not compile
+                errors.append(f"({bn}, {bd}): {type(e).__name__}: {e}")
                 continue
             if t < best_t:
                 best, best_t = (bn, bd), t
-        if best is not None:
-            with _cache_lock:
-                _load_cache()[key] = {"bn": best[0], "bd": best[1],
-                                      "seconds": best_t}
-                _save_cache()
-            _count_dispatch(kernel, "autotune")
-            return best
+        if best is None:
+            # every candidate failed: the kernel does not run here, and the
+            # heuristic would only hide that until the real call fails
+            raise RuntimeError(f"autotune of {key}: every candidate "
+                               "failed\n" + "\n".join(errors))
+        with _cache_lock:
+            _load_cache()[key] = {"bn": best[0], "bd": best[1],
+                                  "seconds": best_t}
+            _save_cache()
+        _count_dispatch(kernel, "autotune")
+        return best
     _count_dispatch(kernel, "heuristic")
     return heuristic_blocks(kernel, n, d, interpret)
 

@@ -37,9 +37,12 @@ from repro.kernels import backend
 
 
 def _hat_weights(n_start, bn, r, h, dtype=jnp.float32):
-    """(bn, r) linear-interp weights for global positions n_start..+bn."""
-    i = jax.lax.broadcasted_iota(jnp.float32, (bn, r), 0) + n_start
-    j = jax.lax.broadcasted_iota(jnp.float32, (bn, r), 1)
+    """(bn, r) linear-interp weights for global positions n_start..+bn.
+    Mosaic's iota is integer-only, so positions are built in int32 and
+    converted."""
+    i = (jax.lax.broadcasted_iota(jnp.int32, (bn, r), 0)
+         + n_start).astype(jnp.float32)
+    j = jax.lax.broadcasted_iota(jnp.int32, (bn, r), 1).astype(jnp.float32)
     return jnp.maximum(0.0, 1.0 - jnp.abs(i / h - j)).astype(dtype)
 
 

@@ -43,10 +43,10 @@ coefficient* form a_coef (d, 2r-1) and share the jnp oracle
   row of W has ≤ 2 interpolation taps, so a length-bn sequence tile only
   ever reads a window of ``bw ≈ bn/h + O(1)`` rows of z₂ = A z. The
   kernel computes exactly that window per tile, streaming the Gram as
-  kb = rp/bw Toeplitz **(bw, bw) band blocks** regenerated in VMEM from a
-  (2bw-1) coefficient slice (static shifted slices — no gather), each
-  contracted on the MXU against the matching z chunk. Per-tile VMEM is
-  O(bd·bw²) + the (bd, 2rp-1) coefficient line + the (rp, bd) z tile —
+  kb = rp/bw Toeplitz **(bw, bw) band blocks**, each applied from a
+  (2bw-1) coefficient window as bw static shifted slices (no gather)
+  multiply-added against the matching z chunk on the VPU. Per-tile VMEM
+  is O(bd·bw) + the (2rp-1, bd) coefficient line + the (rp, bd) z tile —
   never an (r, r) panel. Total Gram MACs are b·d·r² across the grid, the
   same as the dense kernel's once-per-d-tile contraction (windows of
   adjacent tiles overlap by ≤ 2 rows).
@@ -209,52 +209,48 @@ def ski_fused_pass2_pallas(x, z, a_dense, filt, causal: bool, *,
 def _windowed_kernel(prev_ref, cur_ref, nxt_ref, z_ref, *rest, m, left, bn,
                      w0_max, bw, h, nb_total, banded):
     if banded:
-        fc_ref, filt_ref, o_ref = rest
+        ac_ref, filt_ref, o_ref = rest
     else:
         filt_ref, o_ref = rest
     ni = pl.program_id(2)
     s = ni * bn
-    sf = s.astype(jnp.float32)
     # first inducing column touched by this tile's hat rows, clamped so the
     # static-width window stays inside the (padded) inducing grid
-    w0 = jnp.clip(jnp.floor(sf / h).astype(jnp.int32), 0, w0_max)
+    w0 = jnp.clip(jnp.floor(s.astype(jnp.float32) / h).astype(jnp.int32),
+                  0, w0_max)
 
     if banded:
-        # z2 window = A[w0:w0+bw, :] z, streamed as kb Toeplitz (bw, bw)
-        # band blocks regenerated from the flipped coefficient line:
-        # A[w0+j, t] = fc[(rp-1-w0) + t - j]  (fc = lag-reversed, padded)
-        fc = fc_ref[...].astype(jnp.float32)             # (bd, 2rp-1)
-        z = z_ref[0].astype(jnp.float32)                 # (rp, bd)
-        bd = fc.shape[0]
-        rp = z.shape[0]
-        s0 = (rp - 1) - w0
+        # z2 window = A[w0:w0+bw, :] z with A[s, t] = ac[rp-1 + s - t]
+        # (ac: the lag line padded to rank rp, lags on sublanes), streamed
+        # over kb chunks of bw inducing columns. Windows are sliced on the
+        # refs: Mosaic lowers no dynamic_slice of a loaded value, and a
+        # dynamic offset only on the sublane axis.
+        bd = ac_ref.shape[1]
+        rp = z_ref.shape[1]
         kb = rp // bw
 
         def body(k, acc):
-            cs = s0 - (bw - 1) + k * bw
-            csl = jax.lax.dynamic_slice(fc, (0, cs), (bd, 2 * bw - 1))
-            # block[c, j, u] = fc[c, s0 + k*bw + u - j]: bw static shifted
-            # slices of the (2bw-1) line — no gather
-            block = jnp.stack(
-                [csl[:, bw - 1 - j:2 * bw - 1 - j] for j in range(bw)],
-                axis=1)                                  # (bd, bw, bw)
-            zc = jax.lax.dynamic_slice(z, (k * bw, 0), (bw, bd)).T
-            return acc + jax.lax.dot_general(
-                block, zc, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)      # (bd, bw)
+            base = rp - bw + w0 - k * bw
+            win = ac_ref[pl.ds(base, 2 * bw - 1), :].astype(jnp.float32)
+            zc = z_ref[0, pl.ds(pl.multiple_of(k * bw, 8), bw), :].astype(
+                jnp.float32)                             # (bw, bd)
+            # A[w0+j, k*bw+u] = win[bw-1-u+j]: bw static shifted slices of
+            # the (2bw-1) window — no gather
+            for u in range(bw):
+                acc = acc + win[bw - 1 - u:2 * bw - 1 - u] * zc[u:u + 1]
+            return acc
 
-        z2w = jax.lax.fori_loop(
-            0, kb, body, jnp.zeros((bd, bw), jnp.float32)).T   # (bw, bd)
+        z2w = jax.lax.fori_loop(0, kb, body,
+                                jnp.zeros((bw, bd), jnp.float32))  # (bw, bd)
     else:
         # FFT-Gram variant: z_ref already holds z2 = A z; just window it
-        bd = z_ref.shape[2]
-        z2w = jax.lax.dynamic_slice(z_ref[0].astype(jnp.float32),
-                                    (w0, 0), (bw, bd))   # (bw, bd)
+        z2w = z_ref[0, pl.ds(w0, bw), :].astype(jnp.float32)   # (bw, bd)
 
     # windowed hat-weight expansion: w[i, j] = hat((s+i)/h - (w0+j)) (MXU)
-    i = jax.lax.broadcasted_iota(jnp.float32, (bn, bw), 0) + sf
-    j = jax.lax.broadcasted_iota(jnp.float32, (bn, bw), 1) + \
-        w0.astype(jnp.float32)
+    i = (jax.lax.broadcasted_iota(jnp.int32, (bn, bw), 0)
+         + s).astype(jnp.float32)
+    j = (jax.lax.broadcasted_iota(jnp.int32, (bn, bw), 1)
+         + w0).astype(jnp.float32)
     wwin = jnp.maximum(0.0, 1.0 - jnp.abs(i / h - j))
     acc = jnp.dot(wwin, z2w, preferred_element_type=jnp.float32)
     acc = _conv_halo_acc(prev_ref, cur_ref, nxt_ref, filt_ref, acc,
@@ -264,7 +260,7 @@ def _windowed_kernel(prev_ref, cur_ref, nxt_ref, z_ref, *rest, m, left, bn,
 
 @functools.partial(jax.jit, static_argnames=(
     "left", "h", "w0_max", "banded", "interpret", "bn", "bd", "bw"))
-def _windowed_call(x, z, fc, filt, left: int, h: float, w0_max: int, *,
+def _windowed_call(x, z, ac, filt, left: int, h: float, w0_max: int, *,
                    banded, interpret, bn, bd, bw):
     """Requires n % bn == 0, d % bd == 0, bn >= m, z rows padded to rp
     (a multiple of bw when banded) — all arranged by _windowed_padded."""
@@ -287,9 +283,9 @@ def _windowed_call(x, z, fc, filt, left: int, h: float, w0_max: int, *,
     ]
     args = [x, x, x, z]
     if banded:
-        in_specs.append(pl.BlockSpec((bd, 2 * rp - 1),
-                                     lambda bi, di, ni: (di, 0)))
-        args.append(fc)
+        in_specs.append(pl.BlockSpec((2 * rp - 1, bd),
+                                     lambda bi, di, ni: (0, di)))
+        args.append(ac)
     in_specs.append(pl.BlockSpec((bd, m), lambda bi, di, ni: (di, 0)))
     args.append(filt)
 
@@ -317,14 +313,13 @@ def _windowed_padded(x, z, a_coef, filt, left, h, r, banded, interpret,
         filt = jnp.pad(filt, ((0, dp - d), (0, 0)))
     if rp != r or dp != d:
         z = jnp.pad(z, ((0, 0), (0, rp - r), (0, dp - d)))
-    fc = None
+    ac = None
     if banded:
-        # lag-reversed coefficients (A[s,t] lookup becomes a forward slice),
-        # symmetric-padded to rank rp: extra |lag| >= r coefficients are
-        # zero, so padded z rows / window rows contribute exactly nothing
-        fc = jnp.flip(a_coef, axis=-1)
-        fc = jnp.pad(fc, ((0, dp - d), (rp - r, rp - r)))
-    out = _windowed_call(x, z, fc, filt, left, h, w0_max, banded=banded,
+        # lags symmetric-padded to rank rp (extra |lag| >= r coefficients
+        # are zero, so padded z rows / window rows contribute exactly
+        # nothing) and laid out (2rp-1, d): lags on sublanes
+        ac = jnp.pad(a_coef, ((0, dp - d), (rp - r, rp - r))).T
+    out = _windowed_call(x, z, ac, filt, left, h, w0_max, banded=banded,
                          interpret=interpret, bn=bn, bd=bd, bw=bw)
     return out[:, :n, :d]
 

@@ -1,8 +1,8 @@
 """Trainable fused SKI-TNO: custom VJP with Pallas backward kernels (PR 2).
 
-``pallas_call`` has no autodiff in this JAX version, so before this module
-``jax.grad`` through the fused two-pass pipeline silently required the jnp
-reference path. Every factor of the pipeline is *linear in the signal*,
+The kernels define their own backward: a custom VJP whose cotangents are
+kernel launches, so ``jax.grad`` through the fused two-pass pipeline stays
+on the kernel path. Every factor of the pipeline is *linear in the signal*,
 so the backward is the transposed pipeline and reuses the forward
 machinery (Qin et al. 2023's TNN training at kernel speed):
 

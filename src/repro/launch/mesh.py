@@ -22,10 +22,12 @@ def make_mesh(shape, axes) -> Mesh:
     return jax.make_mesh(tuple(shape), tuple(axes))
 
 
-def make_host_mesh() -> Mesh:
-    """1-device mesh for CPU smoke runs (axes present, extent 1)."""
-    dev = np.array(jax.devices()[:1]).reshape(1, 1)
-    return Mesh(dev, ("data", "model"))
+def make_host_mesh(devices=None) -> Mesh:
+    """(data=N, model=1) data-parallel mesh over ``devices`` (default:
+    every device of this host — one on a CPU or a one-chip host, four on
+    a v5e 2x2 host)."""
+    devs = jax.local_devices() if devices is None else list(devices)
+    return Mesh(np.array(devs).reshape(len(devs), 1), ("data", "model"))
 
 
 def data_axes_of(mesh: Mesh):

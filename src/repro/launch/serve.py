@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduce_for_smoke
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import StepBuilder
 from repro.models import serving
@@ -158,6 +159,7 @@ def main(argv=None):
         ap.error("--top-k requires --engine (the solo path samples the "
                  "full distribution)")
 
+    compile_cache.configure()
     from repro.obs import metrics as obs_metrics
     from repro.obs import tracing as obs_tracing
     reg = None
@@ -233,6 +235,13 @@ def main(argv=None):
                 print(f"[serve] trace: {args.trace_file} (JSONL), "
                       f"{chrome} (Perfetto)")
             _dump_metrics(reg, args.metrics_file)
+            # a request that failed (a kernel that did not compile, a
+            # non-finite guard) must fail the run; only a chaos run
+            # expects error outcomes
+            failed = {s: c for s, c in by_status.items() if s != "ok"}
+            if failed and args.chaos is None:
+                print(f"[serve] FAILED: outcomes {failed}")
+                return 1
             return 0
         t0 = time.time()
         toks = generate(sb, params, prompt, args.gen_len,

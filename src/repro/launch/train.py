@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs import get_config, reduce_for_smoke
 from repro.data.pipeline import DataConfig
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import StepBuilder
 from repro.optim import adamw
@@ -37,7 +38,7 @@ TPU_XLA_FLAGS = (
     "--xla_enable_async_all_gather=true")
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
@@ -68,25 +69,15 @@ def main(argv=None):
                     help="stream train_step span events to PATH as JSONL "
                          "and write a Chrome trace_event export "
                          "(PATH + '.chrome.json') on exit")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    if os.environ.get("JAX_COORDINATOR"):
-        jax.distributed.initialize()           # multi-host fleet entry
 
-    from repro.obs import metrics as obs_metrics
-    from repro.obs import tracing as obs_tracing
-    reg = None
-    if args.metrics_file is not None:
-        reg = obs_metrics.Registry()
-        # process default too: backend dispatch counters, the StepBuilder
-        # compile watchdog, and the Trainer's own counters all report
-        # into the same dump (parity with launch/serve.py)
-        obs_metrics.set_default_registry(reg)
-    tracer = (obs_tracing.Tracer(args.trace_file)
-              if args.trace_file is not None else None)
-    if tracer is not None:
-        obs_tracing.set_default_tracer(tracer)
-
+def run(args, mesh=None, tracer=None):
+    """Train as the parsed ``args`` say: config, mesh (default: every
+    device of this host, data-parallel), StepBuilder and Trainer. Returns
+    (trainer, watch, seconds): ``trainer.metrics_history`` and
+    ``trainer.step_seconds`` hold every step, ``watch`` counts the
+    train_step compiles."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)
@@ -94,8 +85,9 @@ def main(argv=None):
         import dataclasses
         cfg = dataclasses.replace(cfg, mixer_override=args.mixer)
 
-    mesh = (make_production_mesh() if args.production_mesh
-            else make_host_mesh())
+    if mesh is None:
+        mesh = (make_production_mesh() if args.production_mesh
+                else make_host_mesh())
     opt_cfg = adamw.OptConfig(lr=args.lr, warmup_steps=args.warmup,
                               total_steps=args.steps)
     sb = StepBuilder(cfg, mesh, opt_cfg=opt_cfg)
@@ -153,10 +145,37 @@ def main(argv=None):
         t0 = time.time()
         state, end = trainer.run(state, start)
         dt = time.time() - t0
-    steps_done = max(end - start, 1)
+    return trainer, watch, dt
+
+
+def main(argv=None):
+    args = parse_args(argv)
+
+    if os.environ.get("JAX_COORDINATOR"):
+        jax.distributed.initialize()           # multi-host fleet entry
+    compile_cache.configure()
+
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import tracing as obs_tracing
+    reg = None
+    if args.metrics_file is not None:
+        reg = obs_metrics.Registry()
+        # process default too: backend dispatch counters, the StepBuilder
+        # compile watchdog, and the Trainer's own counters all report
+        # into the same dump (parity with launch/serve.py)
+        obs_metrics.set_default_registry(reg)
+    tracer = (obs_tracing.Tracer(args.trace_file)
+              if args.trace_file is not None else None)
+    if tracer is not None:
+        obs_tracing.set_default_tracer(tracer)
+
+    trainer, watch, dt = run(args, tracer=tracer)
+    steps_done = max(len(trainer.step_seconds), 1)
+    final = (trainer.metrics_history[-1] if trainer.metrics_history
+             else {})
     print(f"[train] {steps_done} steps in {dt:.1f}s "
           f"({steps_done / dt:.2f} it/s); final metrics: "
-          f"{ {k: float(v) for k, v in trainer.metrics_history[-1].items()} }")
+          f"{ {k: float(v) for k, v in final.items()} }")
     if watch.count("train_step") > 1:
         print(f"[train] WARNING: train_step retraced "
               f"{watch.count('train_step')}x (expected 1 compile)")

@@ -9,6 +9,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.block import TNNBlockConfig, gtu_apply, gtu_init
 from repro.core.tno import TNOConfig
@@ -40,12 +41,13 @@ def ffn_apply(params, cfg: ArchConfig, ctx: Ctx, x):
     return h @ params["w_down"].astype(x.dtype)
 
 
-def _tno_cfg(cfg: ArchConfig, variant: str, causal: bool) -> TNNBlockConfig:
+def _tno_cfg(cfg: ArchConfig, variant: str, causal: bool,
+             use_pallas: bool | None = None) -> TNNBlockConfig:
     tno = TNOConfig(
         d=cfg.d_model, variant=variant, causal=causal, lam=cfg.tno_lam,
         rpe_hidden=cfg.tno_rpe_hidden, rpe_layers=cfg.tno_rpe_layers,
         rpe_act=cfg.tno_rpe_act, rank=cfg.tno_rank,
-        filter_size=cfg.tno_filter)
+        filter_size=cfg.tno_filter, use_pallas=use_pallas)
     return TNNBlockConfig(cfg.d_model, tno=tno, act=cfg.act)
 
 
@@ -73,9 +75,29 @@ def mixer_apply(params, cfg: ArchConfig, ctx: Ctx, mixer: str, x, *,
         return mb.mamba_apply(params, cfg, ctx, x)
     if mixer in ("tno", "ski", "fd"):
         causal = mask_kind in ("causal", "local")
-        # GTU internals run fp32 (FFTs); keep the residual dtype stable
-        return gtu_apply(params, _tno_cfg(cfg, mixer, causal), x).astype(x.dtype)
+        bcfg = _tno_cfg(cfg, mixer, causal, ctx.use_pallas)
+
+        def gtu(p, h):
+            # GTU internals run fp32 (FFTs); keep the residual dtype stable
+            return gtu_apply(p, bcfg, h).astype(h.dtype)
+        if mixer != "tno":                 # ski / fd dispatch to kernels
+            gtu = _per_device_kernels(ctx, gtu)
+        return gtu(params, x)
     raise ValueError(mixer)
+
+
+def _per_device_kernels(ctx: Ctx, fn):
+    """XLA cannot partition a Mosaic kernel: on a mesh of more than one
+    device, run ``fn(params, x)`` per device under shard_map — batch split
+    over the data axes, params replicated — when the Pallas path is on."""
+    from repro.kernels import backend
+    mesh = ctx.mesh
+    if (mesh is None or mesh.size == 1
+            or not backend.resolve_use_pallas(ctx.use_pallas)):
+        return fn
+    data = tuple(a for a in ctx.data_axes if a in mesh.shape) or None
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(), P(data)),
+                         out_specs=P(data), check_vma=False)
 
 
 def layer_init(key, cfg: ArchConfig, mixer: str, ffn: str, *, cross=False,

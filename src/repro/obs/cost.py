@@ -39,11 +39,6 @@ import math
 import os
 from typing import Dict, Optional
 
-#: per-chip peaks, from benchmarks/roofline.py (TPU v5e class)
-TPU_PEAK_FLOPS = 197e12
-TPU_HBM_BW = 819e9
-TPU_ICI_BW = 50e9
-
 _ENV_CPU_FLOPS = "REPRO_CPU_PEAK_FLOPS"
 _ENV_CPU_BW = "REPRO_CPU_PEAK_BW"
 
@@ -81,21 +76,32 @@ def _env_float(name: str, default: float) -> float:
         raise ValueError(f"{name}={v!r} is not a number") from None
 
 
-def peaks(platform: Optional[str] = None) -> Peaks:
-    """Roofline ceilings for a platform (default: the active backend).
-    TPU numbers are the committed v5e constants; CPU defaults are a
-    deliberately conservative laptop-class estimate, overridable via
-    ``REPRO_CPU_PEAK_FLOPS`` / ``REPRO_CPU_PEAK_BW`` — on CPU the
-    fractions rank kernels, they are not MFU claims."""
-    if platform is None:
-        from repro.kernels import backend
-        platform = backend.platform()
-    if platform == "tpu":
-        return Peaks(TPU_PEAK_FLOPS, TPU_HBM_BW, TPU_ICI_BW)
-    if platform == "gpu":
-        return Peaks(60e12, 1.5e12, 0.0)       # A100-class ballpark
-    return Peaks(_env_float(_ENV_CPU_FLOPS, 5e10),
-                 _env_float(_ENV_CPU_BW, 2e10), 0.0)
+#: Per-chip peaks keyed by ``jax.Device.device_kind``, each with its
+#: source. A device kind not in the table is an error, never a default.
+PEAKS: Dict[str, Peaks] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM; 1,600 Gbit/s chip-to-chip interconnect, here as 50 GB/s a link
+    "TPU v5 lite": Peaks(197e12, 819e9, 50e9),
+}
+#: CPU rehearsal entry (not a device metric): a conservative laptop-class
+#: estimate, overridable via REPRO_CPU_PEAK_FLOPS / REPRO_CPU_PEAK_BW, so
+#: CPU fractions rank kernels and are never MFU claims
+CPU_KIND = "cpu"
+
+
+def peaks(device_kind: Optional[str] = None) -> Peaks:
+    """Roofline ceilings for a ``device_kind`` (default: the first
+    device's). Raises for a kind not in :data:`PEAKS`."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    if device_kind == CPU_KIND:
+        return Peaks(_env_float(_ENV_CPU_FLOPS, 5e10),
+                     _env_float(_ENV_CPU_BW, 2e10), 0.0)
+    if device_kind not in PEAKS:
+        raise KeyError(f"no roofline peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)} (add it with its source)")
+    return PEAKS[device_kind]
 
 
 def dtype_bytes(dtype) -> int:
@@ -377,7 +383,7 @@ def xla_cost(fn, *args, **kwargs) -> Optional[dict]:
 
 
 __all__ = [
-    "Cost", "Peaks", "peaks", "dtype_bytes", "fft_flops",
+    "Cost", "Peaks", "PEAKS", "peaks", "dtype_bytes", "fft_flops",
     "short_conv_cost", "interp_cost", "gram_cost", "rfft_cost",
     "fd_mul_cost", "fd_khat_grad_cost", "hilbert_window_cost",
     "ssd_cost", "attention_decode_cost", "mlp_cost", "lm_head_cost",

@@ -128,6 +128,7 @@ class Trainer:
                      if cfg.ckpt_dir else None)
         self._preempted = False
         self.metrics_history: list = []
+        self.step_seconds: list = []     # wall time of each finished step
 
     # ---------------------------------------------------------- signals
     def _install_signals(self):
@@ -215,6 +216,7 @@ class Trainer:
                 if loss is not None and not np.isfinite(float(loss)):
                     raise FloatingPointError(f"non-finite loss at step {step}")
                 dt = time.perf_counter() - t0
+                self.step_seconds.append(dt)
                 self._m_step_s.observe(dt)
                 if loss is not None:
                     self._m_loss.set(float(loss))

@@ -2,6 +2,7 @@
 subprocess-free reuse: these tests run in the main process only when the
 device count allows; otherwise they validate the pure-python parts)."""
 import numpy as np
+import pytest
 
 import jax
 
@@ -99,3 +100,43 @@ def test_serve_ctx_folds_data_axes_for_batch1():
     # with 1-extent axes everything divides; logic check via big mesh is
     # covered by the dry-run. Here: decode ctx must disable seq-SP.
     assert ctx.decode and not ctx.seq_shard_resid
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_step_builder_use_pallas_reaches_tno(use_pallas):
+    """StepBuilder(use_pallas=...) selects the SKI kernels (interpret mode
+    here) or the jnp reference for the TNO mixers of the model it builds."""
+    import jax.numpy as jnp
+    from repro.configs import reduce_for_smoke
+    from repro.kernels import ski_vjp
+    cfg = reduce_for_smoke(get_config("ski-tnn-lm-wt103"), n_layers=1,
+                           d_model=16, d_ff=32, vocab=64)
+    sb = StepBuilder(cfg, use_pallas=use_pallas)
+    state = sb.init_state(jax.random.PRNGKey(0))
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    ski_vjp.reset_counters()
+    _, metrics = sb.make_train_step()(state, {"tokens": tokens,
+                                              "labels": tokens})
+    assert np.isfinite(float(metrics["loss"]))
+    assert ski_vjp.counters["fwd"] == (1 if use_pallas else 0)
+    assert ski_vjp.counters["bwd_kernel"] == (1 if use_pallas else 0)
+
+
+@pytest.mark.parametrize("broken", [False, True])
+def test_serve_exit_code_reflects_outcomes(monkeypatch, capsys, broken):
+    """launch/serve --engine exits non-zero when a request fails (here a
+    prefill that raises, as a kernel that does not compile would)."""
+    from repro.launch import compile_cache, serve
+    from repro.serving_engine import Engine
+    monkeypatch.setattr(compile_cache, "configure", lambda: None)
+    if broken:
+        def refuse(*args, **kwargs):
+            raise RuntimeError("kernel refused")
+        monkeypatch.setattr(Engine, "prefill", refuse)
+        monkeypatch.setattr(Engine, "prefill_packed", refuse)
+    rc = serve.main(["--arch", "fd-tnn-lm-wt103", "--smoke", "--engine",
+                     "--slots", "2", "--batch", "2", "--prompt-len", "8",
+                     "--gen-len", "4"])
+    out = capsys.readouterr().out
+    assert rc == (1 if broken else 0), out
+    assert ("FAILED" in out) == broken

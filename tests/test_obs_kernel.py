@@ -82,8 +82,11 @@ def test_cost_arithmetic():
 
 
 def test_peaks_platforms_and_env_override(monkeypatch):
-    assert obs_cost.peaks("tpu").flops == obs_cost.TPU_PEAK_FLOPS
-    assert obs_cost.peaks("tpu").collective_bw > 0
+    v5e = obs_cost.peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.mem_bw) == (197e12, 819e9)
+    assert v5e.collective_bw > 0
+    with pytest.raises(KeyError, match="TPU v4"):
+        obs_cost.peaks("TPU v4")              # unknown kind: no default
     monkeypatch.setenv("REPRO_CPU_PEAK_FLOPS", "1e11")
     monkeypatch.setenv("REPRO_CPU_PEAK_BW", "4e10")
     pk = obs_cost.peaks("cpu")

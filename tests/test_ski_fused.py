@@ -178,6 +178,24 @@ def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
     backend.clear_cache(memory_only=True)
 
 
+def test_autotune_raises_when_every_candidate_fails(tmp_path, monkeypatch):
+    """A sweep in which no candidate runs must not fall back to the
+    heuristic in silence: the kernel does not run on this device."""
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "tune.json"))
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
+    backend.clear_cache(memory_only=True)
+
+    def tune(bn, bd):
+        raise ValueError(f"refused ({bn}, {bd})")
+    try:
+        with pytest.raises(RuntimeError, match="every candidate failed"):
+            backend.get_blocks("short_conv", 96, 16, jnp.float32, True,
+                               tune_call=tune)
+    finally:
+        backend.clear_cache(memory_only=True)
+    assert not (tmp_path / "tune.json").exists()
+
+
 def test_dispatch_policy_env(monkeypatch):
     monkeypatch.setenv("REPRO_USE_PALLAS", "auto")
     assert backend.use_pallas_default() == (backend.platform() == "tpu")
