@@ -12,8 +12,10 @@ Four pieces (ISSUE 9):
   ``trace_event`` JSON for chrome://tracing / Perfetto.
 * :mod:`repro.obs.log` — the one logger every banner routes through
   (``REPRO_LOG_LEVEL``; quiet by default under pytest).
-* :mod:`repro.obs.profiling` — opt-in ``jax.profiler`` sessions +
-  annotations around prefill/decode/train steps (``REPRO_PROFILE_DIR``).
+* :mod:`repro.obs.profiling` — ``jax.profiler`` sessions
+  (``REPRO_PROFILE_DIR``) and spans around prefill/decode waves and the
+  trainer's batch read, batch placement, state copy and step, which
+  record under any active profiler session.
 
 The kernel tier (ISSUE 10) sits underneath:
 

@@ -6,9 +6,8 @@ Three concerns, all off-by-default-cheap like the rest of the obs tier:
   in ``kernels/ops.py`` (the same layer that counts
   ``repro_kernel_dispatch_total``) in a ``jax.named_scope`` so the
   kernel name lands in HLO op metadata (→ XLA/TPU profiler attribution
-  on real hardware), plus a ``jax.profiler.TraceAnnotation`` when
-  ``REPRO_PROFILE_DIR`` is armed. Both are trace-time only: zero steady
-  state cost inside a compiled executable.
+  on real hardware). It is trace-time only: zero steady state cost
+  inside a compiled executable.
 * **Attribution** — on a profiled run, :func:`aggregate_chrome` sums
   per-kernel wall seconds out of a Chrome trace (ours or the
   profiler's). On CPU smoke runs — where annotations cannot see device
@@ -36,7 +35,6 @@ from typing import Dict, Iterable, List, Optional
 
 from repro.obs import cost as obs_cost
 from repro.obs import metrics as obs_metrics
-from repro.obs import profiling as obs_prof
 
 #: named_scope prefix for kernel regions — the aggregator keys off it
 KERNEL_SCOPE_PREFIX = "repro_kernel."
@@ -63,14 +61,12 @@ def mem_sample_every() -> int:
 def kernel_region(kernel: str):
     """Mark a kernel dispatch site. ``jax.named_scope`` stamps the
     kernel name into the HLO metadata of every op traced inside (the
-    XLA profiler then attributes device time to it on real hardware);
-    the profiler annotation additionally shows up as a host-side region
-    when a ``REPRO_PROFILE_DIR`` session is live. Runs at trace time
-    only — compiled calls never re-enter it."""
+    XLA profiler then attributes device time to it on real hardware).
+    Runs at trace time only — compiled calls never re-enter it, so a
+    host-side span here would mark compile time, not kernel time."""
     import jax
     with jax.named_scope(KERNEL_SCOPE_PREFIX + kernel):
-        with obs_prof.annotation(KERNEL_SCOPE_PREFIX + kernel):
-            yield
+        yield
 
 
 # ------------------------------------------------ trace aggregation

@@ -1,12 +1,14 @@
-"""Opt-in ``jax.profiler`` hooks around serving/training step regions.
+"""``jax.profiler`` hooks around serving and training regions.
 
-ISSUE 9 tentpole §4: set ``REPRO_PROFILE_DIR=/path`` and the scheduler
-(and trainer) bracket their run loops in a ``jax.profiler`` trace
-session writing TensorBoard-loadable protos there, with named
-``TraceAnnotation`` regions around prefill / decode / train steps so
-the device timeline is attributable to serving phases. With the env
-unset every hook is a no-op ``nullcontext`` — zero overhead, nothing
-imported beyond this module.
+Set ``REPRO_PROFILE_DIR=/path`` and the scheduler and the trainer
+bracket their run loops in a ``jax.profiler`` trace session writing
+TensorBoard-loadable protos there. Inside the loops, :func:`annotation`
+spans (prefill and decode waves, the trainer's batch read, batch
+placement, state copy and step) put the host's work on the profiler's
+own clock, beside the device's ops. A span records whenever some
+profiler session is active, whoever started it (this module's
+``session``, a benchmark's capture, TensorBoard's on-demand capture);
+with none active it records nothing and costs one small object.
 
 The profiler can genuinely fail to start (no profiler plugin in a
 stripped CPU wheel, a second concurrent session, a read-only dir);
@@ -57,15 +59,12 @@ def session(name: str = "run"):
 
 
 def annotation(name: str):
-    """Named sub-region (shows as a band on the profiler timeline).
-    Cheap nullcontext when no profile dir is configured."""
-    if profile_dir() is None:
-        return contextlib.nullcontext()
+    """A named span: a band on the host timeline of any active profiler
+    session, and nothing without one. It takes no keyword arguments, so
+    the name reads back from the trace as written; a step's spans are
+    told apart by their order on the host thread."""
     import jax
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 __all__ = ["profile_dir", "session", "annotation"]

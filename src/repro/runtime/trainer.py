@@ -121,8 +121,6 @@ class Trainer:
             "repro_train_step_seconds", "train_step wall time")
         self._m_loss = m.gauge(
             "repro_train_loss", "last finite training loss")
-        self._m_tok_s = m.gauge(
-            "repro_train_tokens_per_s", "training throughput, last step")
         self.monitor = StragglerMonitor(cfg.straggler_factor, cfg.ema_alpha)
         self.ckpt = (ckpt.AsyncCheckpointer(cfg.ckpt_dir, cfg.keep_ckpts)
                      if cfg.ckpt_dir else None)
@@ -176,7 +174,10 @@ class Trainer:
         try:
             step = start_step
             while step < self.cfg.total_steps and not self._preempted:
-                batch = self.put_batch(batch_at(self.data_cfg, step))
+                with obs_prof.annotation("repro.train.read_batch"):
+                    host_batch = batch_at(self.data_cfg, step)
+                with obs_prof.annotation("repro.train.put_batch"):
+                    batch = self.put_batch(host_batch)
                 state, metrics = self._step_with_retry(step, state, batch)
                 self.metrics_history.append(metrics)
                 self._m_steps.inc()
@@ -201,8 +202,9 @@ class Trainer:
         if self.cfg.max_retries > 0 and self.cfg.undonated_retry_copy:
             # donated-buffer hazard: keep a host-side reference so a
             # retry never reuses buffers a failed attempt invalidated
-            backup = jax.tree.map(
-                lambda x: np.asarray(jax.device_get(x)), state)
+            with obs_prof.annotation("repro.train.state_copy"):
+                backup = jax.tree.map(
+                    lambda x: np.asarray(jax.device_get(x)), state)
         for attempt in range(self.cfg.max_retries + 1):
             try:
                 if attempt > 0 and backup is not None:
@@ -210,19 +212,18 @@ class Trainer:
                 if self.failure_hook is not None:
                     self.failure_hook(step, attempt)
                 t0 = time.perf_counter()
-                with obs_prof.annotation("train_step"):
+                # the loss read syncs on the step's device work
+                with obs_prof.annotation("repro.train.step"):
                     new_state, metrics = self.train_step(state, batch)
-                loss = metrics.get("loss")
-                if loss is not None and not np.isfinite(float(loss)):
+                    loss = metrics.get("loss")
+                    loss = None if loss is None else float(loss)
+                if loss is not None and not np.isfinite(loss):
                     raise FloatingPointError(f"non-finite loss at step {step}")
                 dt = time.perf_counter() - t0
                 self.step_seconds.append(dt)
                 self._m_step_s.observe(dt)
                 if loss is not None:
-                    self._m_loss.set(float(loss))
-                if isinstance(batch, dict) and "tokens" in batch and dt > 0:
-                    self._m_tok_s.set(
-                        float(np.asarray(batch["tokens"]).size) / dt)
+                    self._m_loss.set(loss)
                 if self.monitor.observe(step, dt):
                     self._m_stragglers.inc()
                     self.log(f"[trainer] straggler: step {step} took {dt:.3f}s "
