@@ -11,11 +11,16 @@ Responsibilities (DESIGN §5 "1000+-node posture"):
 * **Step retry** — transient failures (injected in tests via
   ``failure_hook``; on real fleets: ICI timeouts, host OOM) retry the
   same step up to ``max_retries`` times. The data pipeline is stateless
-  so a retried step re-reads the identical batch, and because
-  ``train_step`` donates its state buffers, retries rebuild the state
-  from an undonated host-side copy taken before the attempt
-  (``undonated_retry_copy``) — never from buffers a failed attempt may
-  have invalidated.
+  so a retried step re-reads the identical batch. A ``train_step`` that
+  donates its state consumes the input buffers, so a retry must never
+  replay on them: while the step donates, or before any step has
+  finished, the state is copied to the host before each step
+  (``undonated_retry_copy``) and a retry rebuilds it from that copy,
+  each leaf with its own sharding. Whether the step donates is read off
+  the step itself: after each successful call, a deleted input leaf
+  means it does. A step that does not donate leaves its input alive
+  whatever happens in the call, so later steps take no copy and a retry
+  starts from the live state.
 * **Straggler monitor** — per-step wall time EMA; steps slower than
   ``straggler_factor``× the EMA are logged with their step index. On a
   real fleet this feeds the scheduler's hot-spare swap; here it is a
@@ -56,12 +61,13 @@ class TrainerConfig:
     straggler_factor: float = 3.0
     ema_alpha: float = 0.1
     log_every: int = 10
-    # train_step is jit'd with donated state: a step that fails *after*
-    # the call consumed its buffers leaves `state` invalidated, so a
-    # naive retry replays the step on dead arrays. When retries are
-    # enabled this keeps an undonated host-side copy of the state and
-    # rebuilds from it on retry (cost: one host transfer per step —
-    # disable for max-throughput runs that accept retry-unsafety).
+    # A train_step that donates its state and then fails leaves `state`
+    # invalidated, so a naive retry replays the step on dead arrays. When
+    # retries are enabled this keeps a host-side copy of the state on the
+    # first step and then on every step while the step is seen to donate,
+    # and rebuilds from it on retry (cost: one host transfer per such
+    # step; a step that does not donate pays it once). False turns the
+    # copy off, for runs that accept retry-unsafety.
     undonated_retry_copy: bool = True
 
 
@@ -92,8 +98,10 @@ class Trainer:
                  failure_hook: Optional[Callable[[int, int], None]] = None,
                  log: Optional[Callable[[str], None]] = None,
                  metrics=None):
-        """``train_step(state, batch) -> (state, metrics)`` must be jit'd
-        with donated state. ``put_batch(host_batch) -> device batch``
+        """``train_step(state, batch) -> (state, metrics)``, typically
+        jit'd; it may donate its state or not, and the Trainer learns
+        which from the first step (``undonated_retry_copy``).
+        ``put_batch(host_batch) -> device batch``
         places host numpy onto the mesh (identity by default).
         ``failure_hook(step, attempt)`` may raise to inject failures.
         ``metrics`` is an obs registry (default: the process registry —
@@ -112,6 +120,9 @@ class Trainer:
             "repro_train_steps_total", "training steps completed")
         self._m_retries = m.counter(
             "repro_train_retries_total", "training step retries")
+        self._m_copies = m.counter(
+            "repro_train_state_copies_total",
+            "host copies of the train state taken for retry")
         self._m_stragglers = m.counter(
             "repro_train_stragglers_total", "steps flagged as stragglers")
         self._m_ckpts = m.counter(
@@ -127,6 +138,9 @@ class Trainer:
         self._preempted = False
         self.metrics_history: list = []
         self.step_seconds: list = []     # wall time of each finished step
+        # whether train_step donates its state: None until a step has
+        # finished, then whether that step deleted an input leaf
+        self._donates: Optional[bool] = None
 
     # ---------------------------------------------------------- signals
     def _install_signals(self):
@@ -198,17 +212,24 @@ class Trainer:
 
     def _step_with_retry(self, step: int, state: Any, batch: Any):
         last_err: Optional[BaseException] = None
+        guard = self.cfg.max_retries > 0 and self.cfg.undonated_retry_copy
         backup = None
-        if self.cfg.max_retries > 0 and self.cfg.undonated_retry_copy:
-            # donated-buffer hazard: keep a host-side reference so a
-            # retry never reuses buffers a failed attempt invalidated
+        if guard and self._donates is not False:
+            # the step may donate: keep a host-side copy so a retry never
+            # reuses buffers a failed attempt invalidated
             with obs_prof.annotation("repro.train.state_copy"):
-                backup = jax.tree.map(
-                    lambda x: np.asarray(jax.device_get(x)), state)
+                backup = jax.tree.map(_host_copy, state)
+            self._m_copies.inc()
+        like = state
         for attempt in range(self.cfg.max_retries + 1):
+            if attempt > 0 and backup is not None:
+                state = jax.tree.map(_put_like, backup, like)
+            elif attempt > 0 and guard and _any_deleted(state):
+                raise RuntimeError(
+                    f"step {step}: attempt {attempt - 1} deleted its input "
+                    f"state, but earlier steps did not donate it, so no "
+                    f"host copy was taken to retry from")
             try:
-                if attempt > 0 and backup is not None:
-                    state = jax.tree.map(jnp.asarray, backup)
                 if self.failure_hook is not None:
                     self.failure_hook(step, attempt)
                 t0 = time.perf_counter()
@@ -224,6 +245,8 @@ class Trainer:
                 self._m_step_s.observe(dt)
                 if loss is not None:
                     self._m_loss.set(loss)
+                if guard:
+                    self._donates = _any_deleted(state)
                 if self.monitor.observe(step, dt):
                     self._m_stragglers.inc()
                     self.log(f"[trainer] straggler: step {step} took {dt:.3f}s "
@@ -237,3 +260,25 @@ class Trainer:
         raise RuntimeError(
             f"step {step} failed after {self.cfg.max_retries + 1} attempts"
         ) from last_err
+
+
+def _any_deleted(tree: Any) -> bool:
+    """Whether a leaf of ``tree`` is a deleted array (a donated buffer);
+    leaves that cannot be deleted, such as numpy arrays, count as alive."""
+    return any(getattr(x, "is_deleted", lambda: False)()
+               for x in jax.tree.leaves(tree))
+
+
+def _host_copy(x: Any) -> np.ndarray:
+    """``x`` on the host, in memory of its own. The CPU backend can hand
+    back a view of the device buffer itself, and a backup holding that
+    view keeps a donating step from consuming the buffer."""
+    h = np.asarray(jax.device_get(x))
+    return h if h.flags.owndata else h.copy()
+
+
+def _put_like(host: Any, like: Any) -> Any:
+    """``host``, a copy of ``like``, back on ``like``'s devices and sharding."""
+    if isinstance(like, jax.Array):
+        return jax.device_put(host, like.sharding)
+    return jnp.asarray(host)

@@ -608,11 +608,17 @@ def test_annotation_open_when_the_session_stops_is_not_recorded(
     assert _spans(tmp_path) == ["repro.test.closed"]
 
 
-@pytest.mark.parametrize("backup", [True, False])
-def test_trainer_spans_once_per_step_in_order(backup, monkeypatch, tmp_path):
+@pytest.mark.parametrize("backup,donate,copies", [
+    (True, True, [1, 1, 1]),
+    (True, False, [1, 0, 0]),
+    (False, False, [0, 0, 0]),
+], ids=["donating", "undonated", "off"])
+def test_trainer_spans_once_per_step_in_order(backup, donate, copies,
+                                              monkeypatch, tmp_path):
     """Under a session started outside the program, each step records its
-    batch read, batch placement, state copy (only where the undonated
-    backup is kept) and step, in that order."""
+    batch read, batch placement, state copy (only where the host backup
+    is kept: on the first step, and then on every step while the step
+    donates its state) and step, in that order."""
     from repro.data.pipeline import DataConfig
     from repro.runtime.trainer import Trainer, TrainerConfig
     monkeypatch.delenv("REPRO_PROFILE_DIR", raising=False)
@@ -620,18 +626,23 @@ def test_trainer_spans_once_per_step_in_order(backup, monkeypatch, tmp_path):
     def train_step(state, batch):
         return state + 1, {"loss": 1.0 / (state + 1.0)}
 
+    if donate:
+        train_step = jax.jit(train_step, donate_argnums=0)
+    reg = Registry()
     tr = Trainer(TrainerConfig(total_steps=3, log_every=0,
                                undonated_retry_copy=backup),
                  train_step,
                  DataConfig(vocab=16, global_batch=2, seq_len=4, seed=0),
-                 metrics=Registry())
+                 metrics=reg)
     jax.profiler.start_trace(str(tmp_path))
     try:
         _, done = tr.run(jax.numpy.float32(0.0))
     finally:
         jax.profiler.stop_trace()
     assert done == 3
-    step = ["repro.train.read_batch", "repro.train.put_batch",
-            *(["repro.train.state_copy"] if backup else []),
-            "repro.train.step"]
-    assert _spans(tmp_path, "repro.train.") == 3 * step
+    spans = []
+    for n in copies:
+        spans += ["repro.train.read_batch", "repro.train.put_batch",
+                  *(["repro.train.state_copy"] * n), "repro.train.step"]
+    assert _spans(tmp_path, "repro.train.") == spans
+    assert reg.get("repro_train_state_copies_total").get() == sum(copies)

@@ -314,3 +314,99 @@ def test_trainer_retry_unsafe_without_undonated_copy(tmp_path):
     tr = Trainer(tcfg, donating_step, dcfg)
     with pytest.raises(RuntimeError, match="failed after"):
         tr.run({"w": jnp.arange(4, dtype=jnp.float32)})
+
+
+# ------------------------------------------- host copy only while donating
+def _w_step(state, batch):
+    tok = jnp.mean(batch["tokens"].astype(jnp.float32))
+    return ({"w": state["w"] * 0.5 + tok},
+            {"loss": jnp.sum(jnp.abs(state["w"]))})
+
+
+def _retry_run(jitted, fail_at=None):
+    """Six steps of ``jitted`` from a state on a 1-device mesh sharding;
+    with ``fail_at``, attempt 0 of that step raises after the call
+    returned. Returns (final w, registry, the ``w`` each attempt at
+    ``fail_at`` was given)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.obs.metrics import Registry
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    done, given = [], []
+
+    def train_step(state, batch):
+        if len(done) == fail_at:
+            given.append(state["w"])
+        out = jitted(state, batch)
+        if len(given) == 1:
+            raise RuntimeError("injected fault after the step call")
+        done.append(len(done))
+        return out
+
+    reg = Registry()
+    dcfg = DataConfig(vocab=16, seq_len=8, global_batch=2, seed=0)
+    tr = Trainer(TrainerConfig(total_steps=6, log_every=0),
+                 train_step, dcfg, metrics=reg)
+    w0 = jax.device_put(jnp.arange(4, dtype=jnp.float32),
+                        NamedSharding(mesh, P()))
+    out, end = tr.run({"w": w0})
+    assert end == 6
+    return np.asarray(out["w"]), reg, given
+
+
+def test_trainer_undonated_step_copies_state_once_and_retries_live(
+        tmp_path):
+    """A step that does not donate leaves its input alive, so after the
+    first step the Trainer takes no host copy, and a retry after a
+    failure inside the step call starts from the live state and ends
+    where a fault-free run ends."""
+    step = jax.jit(_w_step)
+    clean, _, _ = _retry_run(step)
+    w, reg, given = _retry_run(step, fail_at=3)
+    np.testing.assert_array_equal(w, clean)
+    assert reg.get("repro_train_state_copies_total").get() == 1
+    assert reg.get("repro_train_retries_total").get() == 1
+    assert len(given) == 2 and given[1] is given[0]
+
+
+def test_trainer_donating_jit_copies_every_step_and_rebuilds_sharded(
+        tmp_path):
+    """A real donating jit deletes its input on every call, so the
+    Trainer copies the state before every step; a failure after the
+    donated call at step 3 retries from that copy, put back with the
+    input's sharding, and the run ends exactly where a fault-free one
+    ends."""
+    from jax.sharding import NamedSharding
+    step = jax.jit(_w_step, donate_argnums=0)
+    clean, _, _ = _retry_run(step)
+    w, reg, given = _retry_run(step, fail_at=3)
+    np.testing.assert_array_equal(w, clean)
+    assert reg.get("repro_train_state_copies_total").get() == 6
+    assert reg.get("repro_train_retries_total").get() == 1
+    assert len(given) == 2 and given[1] is not given[0]
+    assert given[1].sharding == given[0].sharding
+    assert isinstance(given[1].sharding, NamedSharding)
+
+
+def test_trainer_refuses_retry_on_state_deleted_without_copy(tmp_path):
+    """A step that starts to delete its input only after the Trainer has
+    seen it not donate has no host copy behind it: the Trainer raises at
+    once and never replays on the dead arrays."""
+    from repro.obs.metrics import Registry
+    calls = []
+
+    def train_step(state, batch):
+        calls.append(len(calls))
+        if len(calls) == 4:
+            for leaf in jax.tree.leaves(state):
+                leaf.delete()
+            raise RuntimeError("consumed its input")
+        return ({"w": state["w"] + 1}, {"loss": jnp.float32(1.0)})
+
+    reg = Registry()
+    dcfg = DataConfig(vocab=16, seq_len=8, global_batch=2, seed=0)
+    tr = Trainer(TrainerConfig(total_steps=6, max_retries=2, log_every=0),
+                 train_step, dcfg, metrics=reg)
+    with pytest.raises(RuntimeError, match="no host copy"):
+        tr.run({"w": jnp.arange(4, dtype=jnp.float32)})
+    assert len(calls) == 4                         # no replay of step 3
+    assert reg.get("repro_train_state_copies_total").get() == 1
