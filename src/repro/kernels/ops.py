@@ -166,12 +166,14 @@ def fd_tno(x, khat_real, *, use_pallas=None, interpret=None):
     staging.
 
     x (b, n, d); khat_real (d, n+1) — the RPE's raw real frequency
-    response on the rfft grid (no decay bias). On the Pallas path the lag
-    window, the complex spectral multiply and the backward's khat
-    reduction are blocked Pallas kernels fused around the XLA FFT stages
-    (kernels/fd_fused.py), and the op carries a custom VJP whose signal
-    cotangent reuses the forward multiply kernel with the spectrum
-    conjugated (causal ⇄ anticausal) — so ``jax.grad`` of a causal FD
+    response on the rfft grid (no decay bias). On the Pallas path the
+    signal's rfft → multiply → irfft runs, where 2n factors
+    (``fd_fused.mxu_split``), as one MXU-DFT kernel per direction with its
+    stages in VMEM, else as XLA FFTs around blocked Pallas kernels; the
+    kernel side's lag window is a Pallas kernel between XLA FFTs
+    (kernels/fd_fused.py). The op carries a custom VJP whose signal
+    cotangent is the forward pipeline with the spectrum conjugated
+    (causal ⇄ anticausal) — so ``jax.grad`` of a causal FD
     block stays on the kernel path, same contract as :func:`ski_fused_tno`
     (counters in fd_fused assert no silent ref fallback). On the
     reference path plain autodiff through ref.fd_tno_ref applies.

@@ -218,3 +218,110 @@ def test_fd_block_grad_parity_pallas_vs_ref():
     flat_r, _ = jax.tree_util.tree_flatten(g_r)
     for a, b in zip(flat_p, flat_r):
         assert _rel(a, b) <= 1e-5
+
+
+# ------------------------------------------- signal-side DFT on the MXU
+def _fd_tno_f64(x, khat, g):
+    """float64 numpy evaluation of the op and its two cotangents: y, dx
+    and dkhat_real for the output cotangent g (the module docstring's
+    formulas, every FFT in float64)."""
+    x, khat, g = (np.asarray(a, np.float64) for a in (x, khat, g))
+    n = x.shape[1]
+    t = np.arange(2 * n)
+    w = np.where((t == 0) | (t == n), 1.0, np.where(t < n, 2.0, 0.0))
+    kc = np.fft.rfft(np.fft.irfft(khat, 2 * n, axis=-1) * w, axis=-1).T
+    xh = np.fft.rfft(x, 2 * n, axis=1)
+    gh = np.fft.rfft(g, 2 * n, axis=1)
+    y = np.fft.irfft(xh * kc, 2 * n, axis=1)[:, :n]
+    dx = np.fft.irfft(gh * np.conj(kc), 2 * n, axis=1)[:, :n]
+    dkt = np.fft.irfft((gh * np.conj(xh)).sum(0).T, 2 * n, axis=-1) * w
+    c = np.full(n + 1, 2.0)
+    c[0] = c[n] = 1.0                      # irfft's adjoint on a real input
+    dk = np.fft.rfft(dkt, axis=-1).real * c / (2 * n)
+    return y, dx, dk
+
+
+@pytest.mark.parametrize("n,n1", [(8192, 128), (1024, 128), (512, 64),
+                                  (256, 32), (128, 16), (4096, 128),
+                                  (33, None), (64, None), (32, None),
+                                  (1536, None), (16384, None)])
+def test_mxu_split(n, n1):
+    """2n = n1·n2 with n1 = 128 (fewer for short n), both multiples of 16
+    and n2 ≤ 128; every other length keeps XLA's FFT."""
+    assert fd_fused.mxu_split(n) == n1
+
+
+MXU_SHAPES = [(2, 256, 128), (1, 1024, 128), (2, 256, 96)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,n,d", MXU_SHAPES)
+def test_fd_tno_mxu_fwd_matches_f64_and_oracle(b, n, d, dtype):
+    x = jax.random.normal(jax.random.PRNGKey(10), (b, n, d)).astype(dtype)
+    khat = jax.random.normal(jax.random.PRNGKey(11), (d, n + 1)).astype(dtype)
+    y = ops.fd_tno(x, khat, use_pallas=True, interpret=True)
+    assert y.dtype == dtype
+    assert fd_fused.counters["fwd_mxu"] == 1
+    assert fd_fused.counters["fwd_xla"] == 0
+    y64 = _fd_tno_f64(x.astype(jnp.float32), khat.astype(jnp.float32),
+                      np.zeros((b, n, d)))[0]
+    tol = GRAD_TOL[jnp.dtype(dtype)]
+    assert _rel(y, y64) <= tol
+    assert _rel(y, ref.fd_tno_ref(x, khat)) <= tol
+
+
+@pytest.mark.parametrize("b,n,d", MXU_SHAPES)
+def test_fd_tno_mxu_grad_matches_f64_and_oracle(b, n, d):
+    """The backward MXU kernel (ĝ and x̂ once each, dx and the batch-summed
+    k̂ cotangent) against float64 numpy and the oracle's autodiff."""
+    ks = jax.random.split(jax.random.PRNGKey(b * n + d), 3)
+    x = jax.random.normal(ks[0], (b, n, d))
+    khat = jax.random.normal(ks[1], (d, n + 1))
+    g = jax.random.normal(ks[2], (b, n, d))
+    _, pull = jax.vjp(lambda x_, k_: ops.fd_tno(
+        x_, k_, use_pallas=True, interpret=True), x, khat)
+    dx, dk = pull(g)
+    assert fd_fused.counters["bwd_mxu"] == 1
+    assert fd_fused.counters["bwd_xla"] == 0
+    _, dx64, dk64 = _fd_tno_f64(x, khat, g)
+    _, pull_ref = jax.vjp(ref.fd_tno_ref, x, khat)
+    dx_ref, dk_ref = pull_ref(g)
+    tol = GRAD_TOL[jnp.dtype(jnp.float32)]
+    assert _rel(dx, dx64) <= tol and _rel(dx, dx_ref) <= tol, "dx mismatch"
+    assert _rel(dk, dk64) <= tol and _rel(dk, dk_ref) <= tol, "dkhat mismatch"
+
+
+@pytest.mark.parametrize("b,n,d,path", [(3, 8192, 512, "mxu"),
+                                        (2, 33, 5, "xla")])
+def test_fd_tno_path_by_shape_traced(b, n, d, path):
+    """Trace only: value-and-grad at train_fd_8k's shape takes the MXU
+    kernels in both directions; an odd length keeps XLA's FFT in both."""
+    x = jax.ShapeDtypeStruct((b, n, d), jnp.float32)
+    khat = jax.ShapeDtypeStruct((d, n + 1), jnp.float32)
+    jax.eval_shape(jax.value_and_grad(
+        lambda x_, k_: jnp.sum(ops.fd_tno(x_, k_, use_pallas=True,
+                                          interpret=True)),
+        argnums=(0, 1)), x, khat)
+    other = "xla" if path == "mxu" else "mxu"
+    assert fd_fused.counters[f"fwd_{path}"] == 1
+    assert fd_fused.counters[f"bwd_{path}"] == 1
+    assert fd_fused.counters[f"fwd_{other}"] == 0
+    assert fd_fused.counters[f"bwd_{other}"] == 0
+
+
+def test_fd_tno_mxu_grad_ref_escape_hatch():
+    """REPRO_PALLAS_GRAD=0 at a length the MXU kernels take: the forward
+    stays on ``fd_mxu_fwd``, the backward is the jnp reference formulas."""
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, 256, 128))
+    khat = jax.random.normal(jax.random.PRNGKey(13), (128, 257))
+    backend.set_default_pallas_grad(False)
+    try:
+        g = jax.grad(lambda x_: jnp.sum(jnp.sin(
+            ops.fd_tno(x_, khat, use_pallas=True, interpret=True))))(x)
+    finally:
+        backend.set_default_pallas_grad(None)
+    g_want = jax.grad(lambda x_: jnp.sum(jnp.sin(ref.fd_tno_ref(x_, khat))))(x)
+    assert _rel(g, g_want) <= 1e-5
+    assert fd_fused.counters["fwd_mxu"] == 1
+    assert fd_fused.counters["bwd_ref"] == 1
+    assert fd_fused.counters["bwd_mxu"] == 0

@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -147,9 +149,7 @@ PARAMS = [pytest.param(name, mode, id=f"{name}-{mode}")
           for mode in (("fwd", "grad") if grad else ("fwd",))]
 
 
-@pytest.mark.parametrize("name,mode", PARAMS)
-def test_kernel_compiles_for_v5e(one_chip, name, mode):
-    fn, shapes, _ = CASES[name]
+def _compiled_text(one_chip, fn, shapes, mode):
     specs = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
              for s in shapes]
     if mode == "fwd":
@@ -159,5 +159,26 @@ def test_kernel_compiles_for_v5e(one_chip, name, mode):
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
             jax.eval_shape(fn, *specs))
         lowered = jax.jit(_vjp_of(fn)).lower(specs, ct)
-    compiled = lowered.compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("name,mode", PARAMS)
+def test_kernel_compiles_for_v5e(one_chip, name, mode):
+    fn, shapes, _ = CASES[name]
+    assert "tpu_custom_call" in _compiled_text(one_chip, fn, shapes, mode)
+
+
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+def test_fd_tno_mxu_kernels_compile_at_train_fd_8k(one_chip, mode):
+    """The FD-TNO op at train_fd_8k's (3, 8192, 512): the signal-side MXU
+    kernel of each direction fits VMEM at the real width, and no array of
+    the (b, ·, d) signal but x, y and their cotangents is left in the
+    program — no padded copy, (b, n+1, d) spectrum or relayout, and no
+    complex re/im join (``X64Combine``). The kernels read and write the
+    signal as (b, n1/2, n2, d) = (3, 64, 128, 512), the same bytes."""
+    b, n, d = 3, 8192, 512
+    text = _compiled_text(one_chip, _fd_tno, [(b, n, d), (d, n + 1)], mode)
+    assert ("fd_mxu_fwd" if mode == "fwd" else "fd_mxu_bwd") in text
+    assert "X64Combine" not in text
+    assert set(re.findall(r"\w+\[3,[0-9,]+\]", text)) <= {
+        "f32[3,8192,512]", "f32[3,64,128,512]"}
