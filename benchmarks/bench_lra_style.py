@@ -6,7 +6,7 @@ compared across the sequence). Bidirectional TNN / SKI-TNN / FD-TNN models
 train for a fixed budget; accuracies land in the paper's qualitative
 ordering territory (all far above chance, within a few points of each
 other). Paper claim checked: SKI/FD reach TNN-level accuracy with the same
-budget while being faster per step (speed covered by bench_tno_variants).
+budget. Their speed per step is a chip measurement, not this script's.
 """
 from __future__ import annotations
 

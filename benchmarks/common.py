@@ -1,45 +1,5 @@
-"""Shared benchmark utilities: wall-clock timing on CPU with jit warmup.
-
-CPU wall-times are meaningful as *ratios between variants measured on the
-same host* (paper's speedup claims are reproduced as such ratios); absolute
-TPU numbers come from the dry-run roofline instead (EXPERIMENTS §Roofline).
-"""
+"""Shared output helper of the paper-quality reproductions."""
 from __future__ import annotations
-
-import time
-
-import jax
-import numpy as np
-
-
-def time_fn(fn, *args, iters=5, warmup=2):
-    """Median wall seconds per call of a jit'd fn (blocks on results)."""
-    for _ in range(warmup):
-        out = fn(*args)
-        jax.block_until_ready(out)
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        ts.append(time.perf_counter() - t0)
-    return float(np.median(ts))
-
-
-def time_fns_interleaved(fns, *args, iters=7, warmup=2):
-    """Best wall seconds per call for several variants, measured in
-    alternating rounds (A B C, A B C, ...) with min-of-rounds — robust to
-    the load drift on shared hosts that sequential medians are not."""
-    for fn in fns:
-        for _ in range(warmup):
-            jax.block_until_ready(fn(*args))
-    best = [float("inf")] * len(fns)
-    for _ in range(iters):
-        for i, fn in enumerate(fns):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            best[i] = min(best[i], time.perf_counter() - t0)
-    return best
 
 
 def report(name: str, value, unit: str, derived: str = ""):

@@ -1,81 +1,45 @@
-"""Benchmark harness — one module per paper table/figure. CSV to stdout:
-``name,value,unit,derived-claim``.
+"""Paper-quality reproductions — one module per paper table or figure.
+CSV to stdout: ``name,value,unit,derived-claim``.
 
-  bench_tno_variants        Figure 1 (+par.5.1/5.2 speed ratios)
-  bench_ski_components      Figure 11 (sparse vs low-rank split) + the
-                            fused-vs-unfused SKI pipeline tracking
-                            (writes BENCH_ski_fused.json at the repo root)
-  bench_fd_fused            causal FD-TNO: fused vs per-stage pipeline +
-                            streaming vs hist-replay decode
-                            (writes BENCH_fd_fused.json at the repo root)
-  bench_engine              continuous-batching engine vs sequential
-                            serving at S ∈ {1,4,16} slots
-                            (writes BENCH_engine.json at the repo root)
-  bench_appendix_b          Appendix B (causal-SKI negative result)
-  bench_pretrain_parity     Table 1 stand-in (causal quality parity)
-  bench_lra_style           Table 2 stand-in (long-range classification)
-  bench_length_extrapolation Fig 7a + par.3.2.2 (inverse warp / FD grids)
-  bench_decay_classes       Appendix E.3 (smoothness => decay, quantified)
+  bench_complexity           §3.2.1/§3.3: compiled HLO FLOPs of each TNO
+                             variant against sequence length
+  bench_pretrain_parity      Table 1 stand-in (causal quality parity)
+  bench_lra_style            Table 2 stand-in (long-range classification)
+  bench_length_extrapolation Fig 7a + §3.2.2 (inverse warp / FD grids)
+  bench_decay_classes        Appendix E.3 (smoothness => decay, quantified)
 
-``--smoke`` runs a fast perf-regression gate (CI): only the fused-vs-
-unfused SKI comparison at n=2048 with reduced iterations.
+They report model quality or compiled FLOPs, never wall time. Speed is
+measured on the chip by the benchmark that ``BENCHMARK.json`` declares
+(``bench/``); PERF.md says what it reads.
 
-Roofline terms for the production mesh come from the dry-run
-(repro.launch.dryrun / results/*.json), not from this harness.
+    PYTHONPATH=src:. python benchmarks/run.py
 """
 from __future__ import annotations
 
 import argparse
 import sys
-import time
 
 
 def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--smoke", action="store_true",
-                    help="fast subset: fused SKI + fused FD perf gates only")
-    args = ap.parse_args()
+    argparse.ArgumentParser(description=__doc__).parse_args()
 
-    print("name,value,unit,derived")
-    if args.smoke:
-        from benchmarks import (bench_engine, bench_fd_fused,
-                                bench_ski_components)
-        t0 = time.time()
-        bench_ski_components.run(smoke=True)
-        print(f"ski_components/_elapsed,{time.time() - t0:.1f},s,")
-        t0 = time.time()
-        bench_fd_fused.run(smoke=True)
-        print(f"fd_fused/_elapsed,{time.time() - t0:.1f},s,")
-        t0 = time.time()
-        bench_engine.run(smoke=True)
-        print(f"engine/_elapsed,{time.time() - t0:.1f},s,")
-        return
-
-    from benchmarks import (bench_appendix_b, bench_complexity,
-                            bench_decay_classes, bench_engine,
-                            bench_fd_fused, bench_length_extrapolation,
-                            bench_lra_style, bench_pretrain_parity,
-                            bench_ski_components, bench_tno_variants)
+    from benchmarks import (bench_complexity, bench_decay_classes,
+                            bench_length_extrapolation, bench_lra_style,
+                            bench_pretrain_parity)
     modules = [
         ("complexity", bench_complexity),
-        ("tno_variants", bench_tno_variants),
-        ("ski_components", bench_ski_components),
-        ("fd_fused", bench_fd_fused),
-        ("engine", bench_engine),
-        ("appendix_b", bench_appendix_b),
         ("pretrain_parity", bench_pretrain_parity),
         ("lra_style", bench_lra_style),
         ("length_extrapolation", bench_length_extrapolation),
         ("decay_classes", bench_decay_classes),
     ]
+    print("name,value,unit,derived")
     for name, mod in modules:
-        t0 = time.time()
         try:
             mod.run()
         except Exception as e:              # report, keep the harness alive
             print(f"{name}/ERROR,0,,{e!r}", file=sys.stderr)
             print(f"{name}/ERROR,0,error,{type(e).__name__}")
-        print(f"{name}/_elapsed,{time.time() - t0:.1f},s,")
 
 
 if __name__ == "__main__":

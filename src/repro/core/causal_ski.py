@@ -4,8 +4,9 @@ The causally-masked low-rank action x'_i = [W A]_i^T s_i with the
 cumulative sums s_i = Σ_{j≤i} w_j x_j requires O(n r) work *and* an
 (b, n, r, d) intermediate. On TPU the serial cumsum maps to
 ``associative_scan`` (log-depth) but the O(n r d) memory/work loss vs
-O(n + r log r) stands — we implement it to *benchmark the negative
-result* (bench_appendix_b), exactly as the paper argues for GPUs.
+O(n + r log r) stands — it is implemented to show the negative result,
+exactly as the paper argues for GPUs. Its speed against FD is a chip
+measurement that no benchmark cell makes yet.
 """
 from __future__ import annotations
 

@@ -30,8 +30,8 @@ How the Gram is applied is ``backend.ski_rank_variant``'s call (PR 3):
 * ``fft``      (beyond) — same op, Gram applied by a length-2r
   rfft/irfft circulant matvec between the two passes.
 
-The 4-kernel unfused form remains as the ``fused=False`` benchmark
-baseline; its Pallas ops are individually custom-VJP'd.
+The 4-kernel unfused form remains behind ``fused=False``, which only
+tests select; its Pallas ops are individually custom-VJP'd.
 
 Forward-invariant pieces (inducing geometry, warped lag grid, Gram
 coefficients / dense Gram) are grouped in a :func:`ski_plan`, built once

@@ -19,12 +19,9 @@ Four pieces (ISSUE 9):
 
 The kernel tier (ISSUE 10) sits underneath:
 
-* :mod:`repro.obs.cost` — analytic per-kernel FLOP/byte estimators keyed
-  off the ski/tno plan objects, roofline math, and the
-  ``cost_analysis()`` cross-check.
-* :mod:`repro.obs.devstats` — kernel regions at the dispatch sites,
-  profiler-trace aggregation / analytic attribution into
-  ``repro_kernel_seconds_total{kernel}``, and HBM/live-buffer gauges.
+* :mod:`repro.obs.devstats` — kernel regions at the dispatch sites (the
+  ``repro_kernel.*`` named scopes the benchmark's trace reader finds a
+  kernel's device time by) and HBM/live-buffer gauges.
 * :mod:`repro.obs.compilewatch` — the compile/retrace watchdog
   (``repro_compiles_total{fn}`` + compile-seconds histogram + budget
   warnings) wrapping the memoised jit entry points.
@@ -37,11 +34,8 @@ from repro.obs.tracing import (Tracer, chrome_trace, default_tracer,
                                validate_spans, write_chrome)
 from repro.obs.log import banner, get_logger, set_level
 from repro.obs.profiling import annotation, profile_dir, session
-from repro.obs.cost import (Cost, Peaks, achieved_fraction, cost_of_plan,
-                            decode_step_cost, peaks, xla_cost)
 from repro.obs.compilewatch import CompileWatch
-from repro.obs.devstats import (aggregate_chrome, attribute_engine,
-                                kernel_region, sample_memory)
+from repro.obs.devstats import kernel_region, sample_memory
 
 __all__ = [
     "Registry", "NullRegistry", "NULL_REGISTRY", "MirroredCounts",
@@ -50,9 +44,6 @@ __all__ = [
     "chrome_trace", "write_chrome", "validate_spans",
     "get_logger", "set_level", "banner",
     "profile_dir", "session", "annotation",
-    "Cost", "Peaks", "peaks", "cost_of_plan", "decode_step_cost",
-    "achieved_fraction", "xla_cost",
     "CompileWatch",
-    "kernel_region", "aggregate_chrome", "attribute_engine",
-    "sample_memory",
+    "kernel_region", "sample_memory",
 ]

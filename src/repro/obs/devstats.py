@@ -1,24 +1,15 @@
-"""Device-time attribution + memory gauges (ISSUE 10 tentpole §2/§3b).
+"""Kernel regions and memory gauges.
 
-Three concerns, all off-by-default-cheap like the rest of the obs tier:
+Two concerns, both off-by-default-cheap like the rest of the obs tier:
 
 * **Kernel regions** — :func:`kernel_region` wraps every dispatch site
   in ``kernels/ops.py`` (the same layer that counts
-  ``repro_kernel_dispatch_total``) in a ``jax.named_scope`` so the
-  kernel name lands in HLO op metadata (→ XLA/TPU profiler attribution
-  on real hardware). It is trace-time only: zero steady state cost
-  inside a compiled executable.
-* **Attribution** — on a profiled run, :func:`aggregate_chrome` sums
-  per-kernel wall seconds out of a Chrome trace (ours or the
-  profiler's). On CPU smoke runs — where annotations cannot see device
-  time — :func:`attribute_engine` takes the *measured* engine seconds
-  (the scheduler's ``repro_decode_step_seconds`` /
-  ``repro_prefill_seconds`` histogram sums) and splits them across
-  kernel families using the analytic share map from
-  :func:`repro.obs.cost.decode_step_cost`. Either path records into
-  ``repro_kernel_seconds_total{kernel}`` and a per-kernel
-  ``repro_kernel_roofline_frac`` gauge, which ``tools/obs_report.py
-  --kernels`` renders.
+  ``repro_kernel_dispatch_total``) and the attention core
+  (``models/attention.py``) in a ``jax.named_scope`` so the
+  kernel name lands in HLO op metadata. The benchmark's trace reader
+  (``bench/trace.py``) finds a kernel's device time through these
+  scopes, so their names are what the per-kernel metrics read. It is
+  trace-time only: zero steady state cost inside a compiled executable.
 * **Memory gauges** — :func:`sample_memory` publishes live device
   bytes, DecodeState cache bytes, and the fd ring/spectra slice of the
   cache as gauges; the scheduler samples it every
@@ -27,16 +18,13 @@ Three concerns, all off-by-default-cheap like the rest of the obs tier:
 from __future__ import annotations
 
 import contextlib
-import gzip
-import json
 import os
-from pathlib import Path
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, Optional
 
-from repro.obs import cost as obs_cost
 from repro.obs import metrics as obs_metrics
 
-#: named_scope prefix for kernel regions — the aggregator keys off it
+#: named_scope prefix for kernel regions — the benchmark's scope reader
+#: keys off it
 KERNEL_SCOPE_PREFIX = "repro_kernel."
 
 _ENV_MEM_EVERY = "REPRO_MEM_SAMPLE_EVERY"
@@ -67,151 +55,6 @@ def kernel_region(kernel: str):
     import jax
     with jax.named_scope(KERNEL_SCOPE_PREFIX + kernel):
         yield
-
-
-# ------------------------------------------------ trace aggregation
-def aggregate_chrome(events: Iterable[dict],
-                     prefix: str = KERNEL_SCOPE_PREFIX) -> Dict[str, float]:
-    """Sum per-kernel seconds from Chrome ``trace_event`` records (the
-    profiler's ``*.trace.json``, or our own exporter's output). Handles
-    complete events (``X`` with ``dur`` µs) and ``B``/``E`` pairs
-    (stacked per (pid, tid, name)). Returns ``{kernel: seconds}`` for
-    events whose name starts with ``prefix`` (stripped)."""
-    totals: Dict[str, float] = {}
-    open_b: Dict[tuple, List[float]] = {}
-    for ev in events:
-        name = ev.get("name", "")
-        if not isinstance(name, str) or not name.startswith(prefix):
-            continue
-        kernel = name[len(prefix):]
-        ph = ev.get("ph")
-        if ph == "X":
-            totals[kernel] = totals.get(kernel, 0.0) \
-                + float(ev.get("dur", 0.0)) * 1e-6
-        elif ph == "B":
-            key = (ev.get("pid"), ev.get("tid"), kernel)
-            open_b.setdefault(key, []).append(float(ev["ts"]))
-        elif ph == "E":
-            key = (ev.get("pid"), ev.get("tid"), kernel)
-            stack = open_b.get(key)
-            if stack:
-                totals[kernel] = totals.get(kernel, 0.0) \
-                    + (float(ev["ts"]) - stack.pop()) * 1e-6
-    return totals
-
-
-def load_profile_traces(profile_dir: str) -> List[dict]:
-    """Collect ``traceEvents`` from every ``*.trace.json[.gz]`` under a
-    ``jax.profiler`` session directory."""
-    events: List[dict] = []
-    root = Path(profile_dir)
-    for p in sorted(root.rglob("*.trace.json")) + \
-            sorted(root.rglob("*.trace.json.gz")):
-        try:
-            if p.suffix == ".gz":
-                with gzip.open(p, "rt") as f:
-                    doc = json.load(f)
-            else:
-                with open(p) as f:
-                    doc = json.load(f)
-        except (OSError, ValueError):
-            continue
-        events.extend(doc.get("traceEvents", []))
-    return events
-
-
-def record_kernel_seconds(seconds_by_kernel: Dict[str, float],
-                          metrics=None) -> None:
-    """Accumulate attributed seconds into
-    ``repro_kernel_seconds_total{kernel}``."""
-    reg = metrics if metrics is not None else obs_metrics.default_registry()
-    m = reg.counter("repro_kernel_seconds_total",
-                    "attributed device/engine seconds per kernel family",
-                    ("kernel",))
-    for kernel, s in seconds_by_kernel.items():
-        if s > 0:
-            m.labels(kernel=kernel).inc(s)
-
-
-# ------------------------------------------------------ attribution
-def _hist_sum(reg, name: str) -> float:
-    m = reg.get(name) if hasattr(reg, "get") else None
-    if m is None or getattr(m, "kind", None) != "histogram":
-        return 0.0
-    with m._lock:
-        return sum(ch.sum for ch in m._children.values())
-
-
-def attribute_engine(engine, metrics, *, drain_s: Optional[float] = None,
-                     profile_dir: Optional[str] = None) -> dict:
-    """Split measured engine seconds across kernel families and record
-    them (tentpole §2's CPU-honest path; acceptance: ≥ 80% of the S=16
-    drain accounted for).
-
-    Ground truth seconds come from the scheduler's own histograms —
-    ``repro_decode_step_seconds`` + ``repro_prefill_seconds`` sums,
-    which time the blocking device calls. When a profiler trace is
-    available (``profile_dir``), per-kernel region seconds are used
-    directly; otherwise the decode seconds are projected onto families
-    by the analytic FLOP shares of one decode step
-    (:func:`repro.obs.cost.decode_step_cost` for the engine's arch —
-    on CPU, where every family is effectively compute-bound, FLOPs are
-    the honest weight). Records ``repro_kernel_seconds_total{kernel}``
-    + ``repro_kernel_roofline_frac{kernel}`` and returns::
-
-        {"device_s", "coverage", "rows": [
-            {"kernel", "seconds", "frac", "roofline_frac"}, ...]}
-
-    ``coverage`` is device_s / drain_s (None when drain_s not given).
-    """
-    step_s = _hist_sum(metrics, "repro_decode_step_seconds")
-    prefill_s = _hist_sum(metrics, "repro_prefill_seconds")
-    device_s = step_s + prefill_s
-
-    by_kernel: Dict[str, float] = {}
-    if profile_dir:
-        by_kernel = aggregate_chrome(load_profile_traces(profile_dir))
-    if not by_kernel and device_s > 0:
-        cfg = engine.cfg
-        costs = obs_cost.decode_step_cost(cfg, engine.slots, engine.max_len)
-        flops_total = sum(c.flops for c in costs.values()) or 1.0
-        by_kernel = {k: step_s * (c.flops / flops_total)
-                     for k, c in costs.items()}
-        if prefill_s > 0:
-            # prefill is one fused forward over the prompt — same family
-            # mix at n=bucket length; reuse the step shares
-            for k, c in costs.items():
-                by_kernel[k] = by_kernel.get(k, 0.0) \
-                    + prefill_s * (c.flops / flops_total)
-    record_kernel_seconds(by_kernel, metrics)
-
-    pk = obs_cost.peaks()
-    costs = obs_cost.decode_step_cost(engine.cfg, engine.slots,
-                                      engine.max_len)
-    # steps executed ≈ decode-step histogram count
-    m = metrics.get("repro_decode_step_seconds") if hasattr(
-        metrics, "get") else None
-    n_steps = 0
-    if m is not None and getattr(m, "kind", None) == "histogram":
-        with m._lock:
-            n_steps = sum(ch.count for ch in m._children.values())
-    frac_gauge = metrics.gauge(
-        "repro_kernel_roofline_frac",
-        "achieved fraction of the roofline bound per kernel family",
-        ("kernel",))
-    total_s = sum(by_kernel.values()) or 1.0
-    rows = []
-    for kernel, s in sorted(by_kernel.items(), key=lambda kv: -kv[1]):
-        rf = None
-        c = costs.get(kernel)
-        if c is not None and n_steps > 0 and s > 0:
-            rf = obs_cost.achieved_fraction(c.scale(n_steps), s, pk)
-            frac_gauge.labels(kernel=kernel).set(rf)
-        rows.append({"kernel": kernel, "seconds": s,
-                     "frac": s / total_s, "roofline_frac": rf})
-    return {"device_s": device_s,
-            "coverage": (device_s / drain_s) if drain_s else None,
-            "rows": rows}
 
 
 # --------------------------------------------------------- memory gauges
@@ -288,6 +131,4 @@ def sample_memory(metrics=None, state=None, *,
 
 
 __all__ = ["kernel_region", "KERNEL_SCOPE_PREFIX", "FD_STREAM_LEAVES",
-           "aggregate_chrome", "load_profile_traces",
-           "record_kernel_seconds", "attribute_engine", "sample_memory",
-           "mem_sample_every"]
+           "sample_memory", "mem_sample_every"]

@@ -3,7 +3,7 @@
 Contracts under test:
 * ragged parity — S slots at staggered lengths emit token-for-token what
   independent solo ``generate`` calls emit (same length bucket), across
-  {fd, tno, attention, mamba} × {fp32, bf16};
+  {fd, tno, attention, mamba} × {fp32, bf16}, and at S = 16 for fd;
 * jit stability — the generate/insert steps trace exactly once across
   steps, inserts, and evictions at fixed S;
 * eviction/recycle — more requests than slots all complete through
@@ -54,6 +54,24 @@ def _solo_tokens(cfg, params, prompts, gens, max_len):
     return outs
 
 
+def _assert_engine_matches_solo(cfg, params, prompts, gens, *, max_len,
+                                slots):
+    """Drive every prompt through one engine of ``slots`` slots and
+    assert each request's tokens equal its solo decode's; returns the
+    engine."""
+    solo = _solo_tokens(cfg, params, prompts, gens, max_len)
+    eng = Engine(cfg, params, slots=slots, max_len=max_len)
+    sched = Scheduler(eng)
+    for i, (pr, g) in enumerate(zip(prompts, gens)):
+        sched.submit(Request(uid=f"r{i}", prompt=pr, max_new=g))
+    res, _ = sched.run()
+    for i in range(len(prompts)):
+        got = np.asarray(res[f"r{i}"])
+        assert np.array_equal(got, solo[i]), (
+            f"r{i}: engine {got} != solo {solo[i]}")
+    return eng
+
+
 # ------------------------------------------------------- ragged parity
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("mixer", sorted(MIXER_ARCHS))
@@ -66,18 +84,22 @@ def test_engine_ragged_parity(mixer, dtype, monkeypatch):
     plens, gens = [3, 6, 5, 2], [8, 5, 6, 9]
     prompts = [rng.integers(0, cfg.vocab, (p,)).astype(np.int32)
                for p in plens]
-    max_len = 24
-    solo = _solo_tokens(cfg, params, prompts, gens, max_len)
+    _assert_engine_matches_solo(cfg, params, prompts, gens, max_len=24,
+                                slots=4)
 
-    eng = Engine(cfg, params, slots=4, max_len=max_len)
-    sched = Scheduler(eng)
-    for i, (pr, g) in enumerate(zip(prompts, gens)):
-        sched.submit(Request(uid=f"r{i}", prompt=pr, max_new=g))
-    res, _ = sched.run()
-    for i in range(len(prompts)):
-        got = np.asarray(res[f"r{i}"])
-        assert np.array_equal(got, solo[i]), (
-            f"{mixer}/{dtype} r{i}: engine {got} != solo {solo[i]}")
+
+def test_engine_parity_and_one_trace_at_16_slots(monkeypatch):
+    """16 requests through S=16 slots == 16 solo decodes, token for
+    token, with staggered budgets, and the decode step traces once."""
+    monkeypatch.setenv("REPRO_FD_STREAM_C", "4")
+    cfg, params = _setup("fd-tnn-lm-wt103", "float32")
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab, (4,)).astype(np.int32)
+               for _ in range(16)]
+    gens = [6 - i % 4 for i in range(16)]
+    eng = _assert_engine_matches_solo(cfg, params, prompts, gens,
+                                      max_len=16, slots=16)
+    assert eng.trace_counts["generate"] == 1, eng.trace_counts
 
 
 def test_engine_eviction_recycle_more_requests_than_slots(monkeypatch):

@@ -4,8 +4,10 @@ No chip is attached: the TPU compiler installed with JAX compiles for a
 topology that is only described (``topologies.get_topology_desc``). A
 compile that passes proves Mosaic accepts the kernel at the paper's
 widths (b=8, n=512, d=512, r=64, m=32) — tile alignment, VMEM budget and
-supported primitives — which interpret mode cannot show. Nothing runs, so
-these tests say nothing about results or times.
+supported primitives — which interpret mode cannot show. It also checks
+that the ``repro_kernel.*`` scopes the benchmark's trace reader finds a
+kernel's device time by survive the compile. Nothing runs, so these tests
+say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -18,8 +20,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import fd_fused, interp_matvec, short_conv, ski_fused
+from repro.kernels import fd_fused, interp_matvec, ops, short_conv, ski_fused
 from repro.kernels import ski_grad, ski_vjp
+from repro.obs.devstats import KERNEL_SCOPE_PREFIX
 
 B, N, D, R, M = 8, 512, 512, 64, 32
 NL, RL = 8192, 2048                  # large-rank SKI widths
@@ -182,3 +185,73 @@ def test_fd_tno_mxu_kernels_compile_at_train_fd_8k(one_chip, mode):
     assert "X64Combine" not in text
     assert set(re.findall(r"\w+\[3,[0-9,]+\]", text)) <= {
         "f32[3,8192,512]", "f32[3,64,128,512]"}
+
+
+# ----------------------------------------- the scopes the benchmark reads
+def _scope_fd_tno(n, mxu):
+    assert (fd_fused.mxu_split(n) is not None) == mxu, n
+
+    def f(x, k):
+        return ops.fd_tno(x, k, use_pallas=True, interpret=False)
+    return f, [(2, n, 128), (128, n + 1)]
+
+
+def _scope_ssd():
+    def f(x, dt, a, b, c, d_skip):
+        return ops.ssd_scan(x, dt, a, b, c, d_skip, chunk=64)
+    bt, n, h, p, g, s = 2, 128, 4, 16, 1, 16
+    return f, [(bt, n, h, p), (bt, n, h), (h,), (bt, n, g, s),
+               (bt, n, g, s), (h,)]
+
+
+def _scope_attention():
+    from repro.configs import get_config, reduce_for_smoke
+    from repro.models import attention
+    from repro.models.context import Ctx
+    from repro.nn.params import unbox
+    cfg = reduce_for_smoke(get_config("granite-4.0-h-micro-p10"),
+                           dtype="float32", param_dtype="float32")
+    params = jax.eval_shape(
+        lambda: unbox(attention.attn_init(jax.random.PRNGKey(0), cfg))[0])
+    leaves, tree = jax.tree.flatten(params)
+
+    def f(x, *leaves):
+        return attention.attn_apply(jax.tree.unflatten(tree, leaves), cfg,
+                                    Ctx(), x)
+    return f, [(2, 64, cfg.d_model)] + [leaf.shape for leaf in leaves]
+
+
+def _scope_short_conv():
+    def f(x, filt):
+        return ops.short_conv(x, filt, True, use_pallas=True,
+                              interpret=False)
+    return f, [(2, 128, 128), (128, 4)]
+
+
+# case -> (scope, builder); fd_tno at a length the MXU kernels take
+# (n = 128) and at one ``mxu_split`` refuses (n = 33), which keeps XLA's FFT
+SCOPE_CASES = {
+    "fd_tno-mxu": ("fd_tno", lambda: _scope_fd_tno(128, True)),
+    "fd_tno-fallback": ("fd_tno", lambda: _scope_fd_tno(33, False)),
+    "ssd": ("ssd", _scope_ssd),
+    "attention": ("attention", _scope_attention),
+    "short_conv": ("short_conv", _scope_short_conv),
+}
+
+
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+@pytest.mark.parametrize("case", list(SCOPE_CASES))
+def test_benchmark_reads_kernel_scope(one_chip, case, mode):
+    """The benchmark's per-kernel metrics find a kernel's device time by
+    the ``repro_kernel.<name>`` scope in the optimised HLO's op metadata
+    (bench/trace.py ``scope_ops``). Each scope must survive the compile
+    for a v5e, and in the backward it must read
+    ``transpose(jvp(repro_kernel.<name>))``, which is how AD names it."""
+    from bench import trace
+    name, build = SCOPE_CASES[case]
+    fn, shapes = build()
+    text = _compiled_text(one_chip, fn, shapes, mode)
+    scope = KERNEL_SCOPE_PREFIX + name
+    assert trace.scope_ops(text, scope), scope
+    if mode == "grad":
+        assert trace.scope_ops(text, f"transpose(jvp({scope}))"), scope

@@ -3,7 +3,7 @@
 Consumes the two ISSUE 9 artifact kinds:
 
 * a **metrics dump** — the Prometheus text exposition or JSON file
-  written by ``--metrics-file`` (launch/serve.py, examples, benchmarks)
+  written by ``--metrics-file`` (launch/serve.py, launch/train.py, examples)
   or ``Registry.dump_*``;
 * a **trace file** — the JSONL span stream written by ``--trace-file``
   or ``REPRO_TRACE_FILE``.
@@ -30,12 +30,9 @@ ISSUE 10 views:
   (``DISAGREE``) — the cheap cross-check that catches histogram
   mirroring bugs.
 * ``--kernels`` — the kernel-tier table: dispatch counts
-  (``repro_kernel_dispatch_total``), attributed seconds
-  (``repro_kernel_seconds_total``) with roofline fractions
-  (``repro_kernel_roofline_frac``), and compile watchdog counts
-  (``repro_compiles_total`` + compile-seconds histogram).
-* ``--bench-trend [BENCH]`` — metric trends from the committed
-  ``benchmarks/history/*.jsonl`` (tools/bench_history.py records).
+  (``repro_kernel_dispatch_total``) and compile watchdog counts
+  (``repro_compiles_total`` + compile-seconds histogram). Kernel device
+  time is read from a chip trace by the benchmark (``bench/``), not here.
 """
 from __future__ import annotations
 
@@ -243,8 +240,8 @@ def compare_latency(trace_report: dict, hists: dict) -> list:
 
 
 def print_kernel_report(metrics_path, out=print) -> None:
-    """The ``--kernels`` view: dispatch counts, attributed seconds with
-    roofline fractions, and compile watchdog counts."""
+    """The ``--kernels`` view: dispatch counts and compile watchdog
+    counts."""
     m = load_metrics(metrics_path)
     hists = load_histograms(metrics_path)
 
@@ -259,18 +256,6 @@ def print_kernel_report(metrics_path, out=print) -> None:
                 r[0].get("kernel", ""), r[0].get("source", ""))):
             out(f"    {labels.get('kernel', '?'):16s} "
                 f"{labels.get('source', '?'):10s} {val:g}")
-    secs = rows_of("repro_kernel_seconds_total")
-    fracs = {r[0].get("kernel"): r[1]
-             for r in rows_of("repro_kernel_roofline_frac")}
-    if secs:
-        total = sum(v for _, v in secs) or 1.0
-        out("  attributed seconds (kernel: seconds, share, roofline "
-            "fraction):")
-        for labels, val in sorted(secs, key=lambda r: -r[1]):
-            k = labels.get("kernel", "?")
-            rf = fracs.get(k)
-            rf_s = f"{rf:.4f}" if rf is not None else "-"
-            out(f"    {k:16s} {val:9.4f}s  {val / total:6.1%}  rf={rf_s}")
     comp = rows_of("repro_compiles_total")
     if comp:
         out("  compiles (fn -> traces):")
@@ -281,17 +266,8 @@ def print_kernel_report(metrics_path, out=print) -> None:
         tot_s = sum(s for _, _, _, s, _ in ch)
         tot_n = sum(n for _, _, _, _, n in ch)
         out(f"  compile wall: {tot_n} timed traces, {tot_s:.3f}s total")
-    if not (disp or secs or comp):
+    if not (disp or comp):
         out("  (no kernel-tier series in this dump)")
-
-
-def print_bench_trend(bench=None, out=print) -> None:
-    """The ``--bench-trend`` view — delegates to tools/bench_history.py
-    (same directory) so the trend math lives in one place."""
-    sys.path.insert(0, str(ROOT / "tools"))
-    import bench_history
-    args = argparse.Namespace(bench=bench, history_dir=None)
-    bench_history.cmd_show(args)
 
 
 def print_report(trace_path=None, metrics_path=None, out=print):
@@ -362,20 +338,10 @@ def main(argv=None) -> int:
                          "violation (CI smoke gate)")
     ap.add_argument("--kernels", action="store_true",
                     help="print the kernel-tier table (dispatch counts, "
-                         "attributed seconds + roofline fractions, "
                          "compile watchdog) from --metrics")
-    ap.add_argument("--bench-trend", nargs="?", const="", default=None,
-                    metavar="BENCH",
-                    help="print benchmarks/history trends (optionally "
-                         "one bench name)")
     args = ap.parse_args(argv)
-    if args.bench_trend is not None:
-        print_bench_trend(args.bench_trend or None)
-        if not args.trace and not args.metrics:
-            return 0
     if not args.trace and not args.metrics:
-        ap.error("nothing to do: pass --trace and/or --metrics "
-                 "(or --bench-trend)")
+        ap.error("nothing to do: pass --trace and/or --metrics")
     if args.kernels and not args.metrics:
         ap.error("--kernels needs --metrics")
     if (args.chrome or args.check) and not args.trace:
